@@ -69,6 +69,9 @@ class AotJit:
         # this wrapper (flops / bytes accessed / arg+temp bytes), or
         # None before the first build
         self.analysis = None
+        # signature -> {module: {HLO instruction name: op_name}} of its
+        # executable (scope_map), or None before the first map
+        self._scopes = None
 
     def undonated_jit(self):
         """The donation-free twin of this program, or None when there
@@ -149,6 +152,23 @@ class AotJit:
             fn = self._build(key, args)
             self._compiled[key] = fn
         return fn
+
+    def scope_map(self) -> dict:
+        """{module name: {HLO instruction name: op_name}} over every
+        executable this wrapper materialised (obs.passcope.
+        scope_map_of): the pass labels of a device trace taken without
+        HLO protos. Read on request only, once per executable, so a run
+        does no HLO-text work unless asked."""
+        from ..obs import passcope
+        if self._scopes is None:
+            self._scopes = {}
+        for key, fn in self._compiled.items():
+            if key not in self._scopes:
+                self._scopes[key] = passcope.scope_map_of(fn) or {}
+        out = {}
+        for m in self._scopes.values():
+            out.update(m)
+        return out
 
     def _build(self, key, args):
         from ..serving import aotcache

@@ -1712,8 +1712,8 @@ class PyEngine:
         ev0 = int(self.stats[:, defs.ST_EVENTS].sum())
         while nt < self.stop and nt < SIMTIME_MAX:
             if TR.ENABLED:
-                _w0 = TR.TRACER.now()
-                _ws = int(nt)
+                _w_tok = TR.TRACER.begin("pyengine.window",
+                                         sim_ns_start=int(nt))
             wend = min(nt + self.min_jump, self.stop)
             executed = False
             nexec = 0
@@ -1748,10 +1748,8 @@ class PyEngine:
                 # the oracle's window loop on the same timeline as the
                 # compiled engine's chunks: span per window (the
                 # tracer's MAX_EVENTS cap bounds long runs)
-                TR.TRACER.complete(
-                    "pyengine.window", _w0,
-                    args={"sim_ns_start": _ws, "sim_ns_end": int(wend),
-                          "events": nexec, "shipped": shipped})
+                TR.TRACER.end(_w_tok, sim_ns_end=int(wend),
+                              events=nexec, shipped=shipped)
             nt_eq = min(self._next_time(h) for h in self.hosts)
             if executed or shipped:
                 # window-advance bound includes carried arrivals

@@ -10,6 +10,7 @@ engine.window.
 
 from __future__ import annotations
 
+import itertools as _itertools
 import time as _time
 from dataclasses import dataclass, field
 
@@ -25,6 +26,7 @@ from ..routing.dns import DNS
 from ..routing.topology import Topology, attach_hosts, build_topology
 from ..apps.compile import compile_app
 from ..net.packet import PKT_WORDS
+from ..obs import trace as TR
 from . import defs
 from .defs import EV_APP, WAKE_START, N_STATS
 from .state import (EngineConfig, Hosts, HostParams, Shared,
@@ -66,6 +68,27 @@ def request_preempt():
     boundary and raise Preempted. Safe to call from a signal handler
     or another thread; a no-op until a run loop observes it."""
     _PREEMPT.set()
+
+
+# the traced runs of this process, numbered: the ``run`` arg of every
+# span a traced run opens (obs.trace tags), so one run's spans share
+# an identifier on a timeline holding several runs
+_RUN_SEQ = _itertools.count(1)
+
+# jitted once per process, not per run: a fresh jax.jit(lambda) in
+# every run would retrace (and look up or compile) inside the run
+# loop. _zeros_like resets the pcap ring on a multi-process mesh.
+_zeros_like = jax.jit(jnp.zeros_like)
+
+
+@jax.jit
+def _ev_sum(stats):
+    """Events executed so far, as a jitted reduction: a replicated
+    scalar on every process (eager ops cannot run on non-addressable
+    global arrays) and one column's sum instead of a full stats
+    gather. Padded inert rows never execute events, so the all-rows
+    sum equals [:H]."""
+    return jnp.sum(stats[:, defs.ST_EVENTS])
 
 
 @dataclass
@@ -424,6 +447,7 @@ class Simulation:
 
     def __init__(self, scenario: Scenario, topology: Topology = None,
                  engine_cfg: EngineConfig = None, seed: int = None):
+        tb = TR.begin("sim.build")
         self.scenario = scenario
         seed = scenario.seed if seed is None else seed
         self.seed = seed
@@ -679,6 +703,7 @@ class Simulation:
             eq_next=jnp.asarray(eq_time.min(axis=1)))
 
         self._ran = False
+        TR.end(tb)
 
     def effective_chunk(self, digest_every: int = 0) -> int:
         """The chunk the window program ACTUALLY compiles for: 1
@@ -884,7 +909,6 @@ class Simulation:
         _PREEMPT.clear()
         from ..obs import digest as DG
         from ..obs import metrics as MT
-        from ..obs import trace as TR
         from ..parallel import dist
         own_tr = own_mt = own_dg = False
         if digest is not None:
@@ -936,6 +960,10 @@ class Simulation:
                 digest_rewind=digest_rewind, netscope=netscope,
                 passcope=passcope)
         finally:
+            if TR.ENABLED:
+                # the run's tags end with it: later spans (the next
+                # Simulation's build) belong to no run
+                TR.TRACER.tags = {}
             if own_tr:
                 TR.finish()
             if own_mt:
@@ -951,13 +979,13 @@ class Simulation:
         from ..obs import digest as DG
         from ..obs import metrics as MT
         from ..obs import passcope as PC
-        from ..obs import trace as TR
         # hot-loop observability guard: with --trace/--metrics off the
         # per-chunk cost of the whole obs layer is this one boolean
         obs_on = TR.ENABLED or MT.ENABLED
         dg = DG.RECORDER if DG.ENABLED else None
         if TR.ENABLED:
-            _s0 = TR.TRACER.now()
+            TR.TRACER.tags = {"run": next(_RUN_SEQ), "chunk": 0}
+            _s_tok = TR.TRACER.begin("run.setup")
         H = self.cfg.num_hosts
 
         from ..parallel import dist
@@ -1105,9 +1133,13 @@ class Simulation:
         inj = self.injector
 
         def dg_record(kind, window, sim_ns):
-            # one digest-chain sample (obs.digest): the state pull is
-            # the whole cadence cost, accounted as a span + metrics
-            _d0 = TR.TRACER.now() if TR.ENABLED else None
+            # one digest-chain sample (obs.digest): the state pull and
+            # the hash are the whole cadence cost, accounted as spans
+            # (digest.record around the recorder's digest.pull and
+            # digest.hash) + metrics
+            if TR.ENABLED:
+                _d_tok = TR.TRACER.begin("digest.record", window=window,
+                                         kind=kind)
             pulled = hosts
             if multiproc:
                 # materialize the GLOBAL state on every process (the
@@ -1121,9 +1153,7 @@ class Simulation:
                       if self.hosting is not None else None)
             dg.record(pulled, H, window, sim_ns, kind, hosted=hosted)
             if TR.ENABLED:
-                TR.TRACER.complete("digest.record", _d0,
-                                   args={"window": window,
-                                         "kind": kind})
+                TR.TRACER.end(_d_tok)
             if MT.ENABLED:
                 reg = MT.REGISTRY
                 reg.counter("digest.records").inc()
@@ -1328,7 +1358,7 @@ class Simulation:
         if TR.ENABLED:
             # everything up to here: topology/mesh placement, writers,
             # checkpoint fingerprint/restore — the pre-loop cost
-            TR.TRACER.complete("run.setup", _s0)
+            TR.TRACER.end(_s_tok)
         # the passcope trace arms at the FIRST chunk_done(), after the
         # cold compile — tracing a multi-minute XLA compile is both
         # ruinously slow and useless to the pass table; the HLO
@@ -1340,14 +1370,6 @@ class Simulation:
         n_chunks = 0     # unconditional (chunk_i only counts with obs
         #   on): the cost model scales the window program's measured
         #   bytes-accessed by how many times the chunk executed
-        # jitted once, called per chunk (multiproc pcap ring reset)
-        _zeros_like = jax.jit(jnp.zeros_like)
-        # per-chunk events total as a jitted reduction: a replicated
-        # scalar on every process (the eager-t0 pattern above — eager
-        # ops cannot run on non-addressable global arrays) and one
-        # column's sum instead of a full stats gather. Padded inert
-        # rows never execute events, so the all-rows sum equals [:H].
-        _ev_sum = jax.jit(lambda s: jnp.sum(s[:, defs.ST_EVENTS]))
         # resumed runs restore pre-checkpoint ST_EVENTS with the state:
         # baseline the per-chunk delta on it or the first chunk's
         # telemetry would claim the whole pre-checkpoint history
@@ -1371,12 +1393,12 @@ class Simulation:
                 saved = False
                 if store is not None:
                     if TR.ENABLED:
-                        _k0 = TR.TRACER.now()
+                        _k_tok = TR.TRACER.begin(
+                            "checkpoint.preempt_save")
                     save_snapshot(int(wstart))
                     saved = True
                     if TR.ENABLED:
-                        TR.TRACER.complete("checkpoint.preempt_save",
-                                           _k0)
+                        TR.TRACER.end(_k_tok)
                 if MT.ENABLED:
                     MT.REGISTRY.counter("engine.preemptions").inc()
                 if self.hosting is not None:
@@ -1401,10 +1423,24 @@ class Simulation:
             if obs_on:
                 _ws0 = int(wstart)
                 _c0 = _time.perf_counter_ns()
+            if TR.ENABLED:
+                # chunk > [compile+first_chunk >] chunk.dispatch (the
+                # step call until it returns) then chunk.wait (until
+                # its results are on the host)
+                TR.TRACER.tags["chunk"] = n_chunks
+                _ck_tok = TR.TRACER.begin("chunk", sim_ns_start=_ws0)
+                if first_chunk_wall is None:
+                    _cf_tok = TR.TRACER.begin("compile+first_chunk")
+                _cd_tok = TR.TRACER.begin("chunk.dispatch")
             hosts, wstart, wend, n, pc = step(hosts, sh_seg, wstart,
                                               wend)
+            if TR.ENABLED:
+                TR.TRACER.end(_cd_tok)
+                _cw_tok = TR.TRACER.begin("chunk.wait")
             total_windows += int(n)
             pc_np = np.asarray(pc)
+            if TR.ENABLED:
+                TR.TRACER.end(_cw_tok)
             if pc_np.ndim == 2:    # sharded: [n_shards, NR] rung mix
                 pass_acc += pc_np.sum(axis=0)
                 if shard_pass_acc is not None:
@@ -1422,11 +1458,11 @@ class Simulation:
                     # where the cold XLA build went (the cost model's
                     # "warm" exclusion) — nested inside the first
                     # chunk span so self-times attribute correctly
-                    TR.TRACER.complete("compile+first_chunk", _c0)
+                    TR.TRACER.end(_cf_tok)
             ws = int(wstart)
             if self.hosting is not None:
                 if TR.ENABLED:
-                    _h0 = TR.TRACER.now()
+                    _h_tok = TR.TRACER.begin("hosting.step")
                 now = min(ws, stop_ns)
                 hosts = self.hosting.step(hosts, hp, sh, now)
                 if mesh is not None:
@@ -1450,10 +1486,10 @@ class Simulation:
                 wend = jnp.where(nt == SIMTIME_MAX, nt, nt + sh.min_jump)
                 ws = int(wstart)
                 if TR.ENABLED:
-                    TR.TRACER.complete("hosting.step", _h0)
+                    TR.TRACER.end(_h_tok)
             if pcap_on_run:
                 if TR.ENABLED:
-                    _p0 = TR.TRACER.now()
+                    _p_tok = TR.TRACER.begin("pcap.drain")
                 # every process participates in the gather (it is a
                 # collective); only process 0 holds a writer
                 tr_t = dist.gather_stats(hosts.tr_time)
@@ -1470,10 +1506,10 @@ class Simulation:
                     hosts = hosts.replace(
                         tr_cnt=jnp.zeros_like(hosts.tr_cnt))
                 if TR.ENABLED:
-                    TR.TRACER.complete("pcap.drain", _p0)
+                    TR.TRACER.end(_p_tok)
             if tracker is not None and tracker.due(min(ws, stop_ns)):
                 if TR.ENABLED:
-                    _t0 = TR.TRACER.now()
+                    _t_tok = TR.TRACER.begin("tracker.heartbeat")
                 from ..obs.tracker import socket_columns
                 # [socket]/[ram] columns are per-process state; under a
                 # multi-process mesh only the stats all-gather exists,
@@ -1489,7 +1525,7 @@ class Simulation:
                                       [:, defs.ST_EVENTS].sum())
                                   )["waste_frac"])
                 if TR.ENABLED:
-                    TR.TRACER.complete("tracker.heartbeat", _t0)
+                    TR.TRACER.end(_t_tok)
             if nsrec is not None:
                 # network time-series sample: one record per chunk,
                 # derived from device state + sim time only (dual-run
@@ -1498,7 +1534,7 @@ class Simulation:
                 # active-conn counting reads per-process socket state,
                 # so it is single-process only (like [socket] lines)
                 if TR.ENABLED:
-                    _n0 = TR.TRACER.now()
+                    _n_tok = TR.TRACER.begin("netscope.sample")
                 nsrec.sample(
                     total_windows, min(ws, stop_ns),
                     np.asarray(dist.gather_stats(hosts.ns_hist))[:H],
@@ -1506,14 +1542,14 @@ class Simulation:
                     conns=(None if multiproc else
                            int(np.asarray(hosts.sk_used).sum())))
                 if TR.ENABLED:
-                    TR.TRACER.complete("netscope.sample", _n0)
+                    TR.TRACER.end(_n_tok)
             if checkpoint_path and ckpt_at is not None and ws >= ckpt_at:
                 if TR.ENABLED:
-                    _k0 = TR.TRACER.now()
+                    _k_tok = TR.TRACER.begin("checkpoint.save")
                 save_snapshot(ws)
                 ckpt_at += next_ckpt
                 if TR.ENABLED:
-                    TR.TRACER.complete("checkpoint.save", _k0)
+                    TR.TRACER.end(_k_tok)
             if crash is not None:
                 # durability-test preemption: lands AFTER the
                 # checkpoint block, so a snapshot due at this boundary
@@ -1530,11 +1566,8 @@ class Simulation:
                 ev = ev_total - prev_events
                 prev_events = ev_total
                 if TR.ENABLED:
-                    TR.TRACER.complete(
-                        "chunk", _c0,
-                        args={"sim_ns_start": _ws0,
-                              "sim_ns_end": sim_end,
-                              "windows": int(n), "events": ev})
+                    TR.TRACER.end(_ck_tok, sim_ns_end=sim_end,
+                                  windows=int(n), events=ev)
                 if MT.ENABLED:
                     reg = MT.REGISTRY
                     reg.counter("engine.chunks").inc()
@@ -1588,7 +1621,7 @@ class Simulation:
                 nf = inj.next_time()
                 if nf is not None and nf < stop_ns and ws >= nf:
                     if TR.ENABLED:
-                        _fi0 = TR.TRACER.now()
+                        _fi_tok = TR.TRACER.begin("faults.apply")
                     hosts, sh = inj.apply_batch(hosts, sh)
                     if mesh is not None:
                         from ..parallel.shard import (put_hosts,
@@ -1602,7 +1635,7 @@ class Simulation:
                                      nt + sh.min_jump)
                     ws = int(wstart)
                     if TR.ENABLED:
-                        TR.TRACER.complete("faults.apply", _fi0)
+                        TR.TRACER.end(_fi_tok)
                     if dg is not None:
                         # fault boundary: sample at the fault's own
                         # sim time — where a broken-determinism hunt
@@ -1623,7 +1656,7 @@ class Simulation:
         if pcap is not None:
             pcap.close()
         if TR.ENABLED:
-            _f0 = TR.TRACER.now()
+            _f_tok = TR.TRACER.begin("report.finalize")
         stats = dist.gather_stats(hosts.stats)[:H]
         wall = _time.perf_counter() - wall0
         self.final_hosts = hosts
@@ -1730,7 +1763,7 @@ class Simulation:
                                    if self.hosting is not None else {}),
                            faults=(inj.log if inj is not None else []))
         if TR.ENABLED:
-            TR.TRACER.complete("report.finalize", _f0)
+            TR.TRACER.end(_f_tok)
         if MT.ENABLED:
             MT.REGISTRY.gauge("engine.first_chunk_wall_s").set(
                 first_chunk_wall or 0.0)

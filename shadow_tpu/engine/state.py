@@ -265,8 +265,9 @@ HOT_FIELDS = (
 # holds for the engine config, because the static pruning already
 # compiles no access to it (the Python `if cfg.*` branches and the
 # app_kinds switch table). Exactness invariant (pinned by
-# tests/test_compaction.py::test_hot_split_gating_bit_identical and
-# the dual-run digest suite): under the guard, the column holds its
+# tests/test_compaction_invariants.py::
+# test_hot_split_gating_bit_identical and the dual-run digest
+# suite): under the guard, the column holds its
 # alloc_hosts default on every row at every instant — the only
 # reachable writes are the sock_alloc/sock_free resets, which write
 # that same default — so the drain's compiled reads of it (e.g.

@@ -188,7 +188,10 @@ class Supervisor:
             argv = self._child_argv(attempt)
             resumed = attempt > 1
             t0 = time.perf_counter()  # simlint: ok DET101 -- attempt wall for the crash journal, not sim time
-            _s0 = TR.TRACER.now() if TR.ENABLED else None
+            if TR.ENABLED:
+                _s_tok = TR.TRACER.begin("supervisor.attempt",
+                                         attempt=attempt,
+                                         resumed=resumed)
             try:
                 rc = subprocess.call(argv)
             except KeyboardInterrupt:
@@ -197,10 +200,7 @@ class Supervisor:
             wall = time.perf_counter() - t0  # simlint: ok DET101 -- attempt wall for the crash journal, not sim time
             cause = classify_exit(rc)
             if TR.ENABLED:
-                TR.TRACER.complete(
-                    "supervisor.attempt", _s0,
-                    args={"attempt": attempt, "exit_status": rc,
-                          "cause": cause, "resumed": resumed})
+                TR.TRACER.end(_s_tok, exit_status=rc, cause=cause)
             if MT.ENABLED:
                 reg = MT.REGISTRY
                 reg.counter("supervisor.attempts").inc()

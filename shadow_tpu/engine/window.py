@@ -501,9 +501,9 @@ def step_window_pass(hosts, hp, sh, wend, cfg: EngineConfig):
     the per-host queue would pop them over consecutive passes, and the
     order the pyengine oracle drains them in). A not-ready row's step
     is the identity (every handler is gated on `ready`; pinned by
-    tests/test_compaction.py::test_idle_step_identity), which makes
-    dummy gather slots (duplicates of one not-ready host) harmless:
-    every duplicate scatter-back writes identical bytes.
+    tests/test_compaction_invariants.py::test_idle_step_identity),
+    which makes dummy gather slots (duplicates of one not-ready host)
+    harmless: every duplicate scatter-back writes identical bytes.
 
     Returns (hosts, rung) where rung indexes ladder_of(cfg) with
     len(ladder) == the dense fallback (pass-mix accounting for the
@@ -1096,6 +1096,18 @@ def run_windows_batch_aot(cfg: EngineConfig, max_windows: int,
                                  f".b{batch}.{fingerprint_of(cfg)}"))
         _RWB_INSTANCES[key] = fn
     return fn
+
+
+def chunk_program_scopes() -> dict:
+    """{module name: {HLO instruction name: op_name}} over the
+    window-chunk programs this process materialized, single and
+    batched (core.jitcache.AotJit.scope_map): what a device trace
+    taken without HLO protos needs to put its ops under the pass
+    labels (obs.passcope.attribute)."""
+    out = {}
+    for fn in [*_RW_INSTANCES.values(), *_RWB_INSTANCES.values()]:
+        out.update(fn.scope_map())
+    return out
 
 
 def run_windows(hosts, hp, sh, wstart, wend, cfg: EngineConfig,

@@ -45,7 +45,10 @@ Cheap when disabled: the module-level ``ENABLED`` boolean is the whole
 cost (the obs.trace/obs.metrics contract); hot paths guard with
 ``if digest.ENABLED:``. Enabled cost is one state pull + one linear
 hash pass per cadence, accounted as a ``digest.record`` span
-(obs.trace) and ``digest.*`` metrics when those recorders are on.
+(obs.trace) around its two parts, ``digest.pull`` (the device→host
+transfer; its ``bytes`` arg counts what moved) and ``digest.hash``
+(canonicalise, hash, chain), and ``digest.*`` metrics when those
+recorders are on.
 """
 
 from __future__ import annotations
@@ -56,6 +59,8 @@ import json
 import os
 
 import numpy as np
+
+from . import trace as TR
 
 ENABLED = False
 RECORDER = None
@@ -275,9 +280,14 @@ class DigestRecorder:
         from ..engine.checkpoint import named_leaves
         from ..engine.window import canonicalize_state
 
-        arrs = {name: np.asarray(leaf)[:H]
-                for name, leaf in named_leaves(hosts)}
-        arrs = canonicalize_state(arrs)
+        if TR.ENABLED:
+            tok = TR.TRACER.begin("digest.pull")
+        full = [(name, np.asarray(leaf))
+                for name, leaf in named_leaves(hosts)]
+        if TR.ENABLED:
+            TR.TRACER.end(tok, bytes=sum(a.nbytes for _, a in full))
+            tok = TR.TRACER.begin("digest.hash")
+        arrs = canonicalize_state({name: a[:H] for name, a in full})
         sections, hosts_hex, nbytes = _hash_arrays(arrs, H,
                                                    self.host_detail)
         self.bytes_hashed += nbytes
@@ -304,6 +314,8 @@ class DigestRecorder:
                                         separators=(",", ":")) + "\n")
             self._file.flush()
         self.next_due = int(window) + self.every
+        if TR.ENABLED:
+            TR.TRACER.end(tok)
         return rec
 
     def close(self):

@@ -475,7 +475,8 @@ def observe_executable(scope: str, compiled, donated=()) -> dict:
         CAPTURED[scope] = out
         return out
     from . import trace as TR
-    t0 = TR.TRACER.now() if TR.ENABLED else None
+    if TR.ENABLED:
+        tok = TR.TRACER.begin("memscope.analyze", scope=scope)
     try:
         ca = _cost_dict(compiled)
         flops = ca.get("flops")
@@ -501,9 +502,7 @@ def observe_executable(scope: str, compiled, donated=()) -> dict:
         out["errors"]["memory_analysis"] = f"{type(e).__name__}: {e}"
     CAPTURED[scope] = out
     if TR.ENABLED:
-        TR.TRACER.complete("memscope.analyze", t0,
-                           args={"scope": scope,
-                                 "available": out["available"]})
+        TR.TRACER.end(tok, available=out["available"])
     from . import metrics as MT
     if MT.ENABLED:
         reg = MT.REGISTRY

@@ -58,6 +58,23 @@ PASS_LABELS = (
 # drain-rung sublabels (engine.window.pass_labels): w<K> window
 # rungs, k<K> per-pass rungs, dense
 _RUNG_RE = re.compile(r"^(?:[wk][0-9]+|dense)$")
+# a scope entered under a transform is rendered inside it on the
+# op_name path: ``vmap(tcp.rx)``, ``vmap(jit(_where))``
+_WRAPPED_RE = re.compile(r"^[\w.]+\((.*)\)$")
+
+
+def _scopes_of(op):
+    """The scope names on an op_name path, transform wrappers peeled:
+    the handlers run vmapped over the drain's lanes, so their labels
+    appear as ``vmap(nic.tx)``."""
+    out = []
+    for part in op.split("/"):
+        m = _WRAPPED_RE.match(part)
+        while m:
+            part = m.group(1)
+            m = _WRAPPED_RE.match(part)
+        out.append(part)
+    return out
 
 MIN_ATTRIBUTED = 0.90
 RESIDUAL = "unattributed (device glue)"
@@ -217,11 +234,21 @@ def _walk_hlo_module(mod):
     return out
 
 
-def hlo_scope_map(path):
+def _module_name(mod):
+    """HloModuleProto f1: the module's name."""
+    for fn, wt, v in _fields(mod):
+        if fn == 1 and wt == 2:
+            return bytes(v).decode("utf-8", "replace")
+    return None
+
+
+def hlo_scope_map(path, module=None):
     """-> {hlo_instruction_name: op_name} over every module the
     profiler recorded (the ``/host:metadata`` plane embeds one
-    serialized HloProto per executed jitted module — XEventMetadata
-    stats carry it as the bytes value)."""
+    serialized HloProto per jitted module the process holds —
+    XEventMetadata stats carry it as the bytes value), or over the
+    module named `module` alone: instruction names repeat across
+    modules."""
     buf = memoryview(open(path, "rb").read())
     scopes = {}
     for fn, wt, v in _fields(buf):
@@ -237,8 +264,34 @@ def hlo_scope_map(path):
                         if fn3 == 6 and wt3 == 2:  # XStat.bytes_value
                             for fn4, wt4, v4 in _fields(v3):
                                 if fn4 == 1 and wt4 == 2:  # HloProto.hlo_module
-                                    scopes.update(_walk_hlo_module(v4))
+                                    if (module is None
+                                            or _module_name(v4) == module):
+                                        scopes.update(_walk_hlo_module(v4))
     return scopes
+
+
+def scope_map_of(compiled):
+    """-> {module name: {hlo_instruction_name: op_name}} read from an
+    executable's own optimised HLO module(s) — compiled here or loaded
+    from the executable cache — the map :func:`hlo_scope_map` reads
+    from a trace's metadata plane, for traces taken without HLO protos
+    (the device's op events carry instruction names only). Keyed by
+    module, since instruction names repeat across modules. None when
+    the executable exposes no HLO module.
+
+    Walks the serialised HloModuleProto with the same decoder as the
+    trace path: one pass over the module, seconds for the window
+    program. Built only on request (core.jitcache.AotJit.scope_map),
+    never by a run itself."""
+    try:
+        mods = compiled.runtime_executable().hlo_modules()
+    except (AttributeError, RuntimeError):
+        return None
+    if not mods:
+        return None
+    return {m.name: _walk_hlo_module(
+                memoryview(m.as_serialized_hlo_module_proto()))
+            for m in mods}
 
 
 # --- per-op SELF time ------------------------------------------------------
@@ -342,7 +395,7 @@ def attribute(selfs, scopes):
         op = scopes.get(hlo)
         label = rung = None
         if op:
-            for part in reversed(op.split("/")):
+            for part in reversed(_scopes_of(op)):
                 if rung is None and _RUNG_RE.match(part):
                     rung = part
                 elif label is None and part in PASS_LABELS:

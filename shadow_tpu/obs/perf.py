@@ -33,22 +33,29 @@ from collections import defaultdict
 # only for wall time NO span covered.
 PHASE_OF = {
     "run.setup": "setup",            # topology/mesh placement, writers
+    "sim.build": "setup",            # Simulation(...)
     "compile+first_chunk": "compile",  # cold XLA build (+ 1st chunk)
     "chunk": "window",               # compiled drain+exchange chunks
+    "chunk.dispatch": "window",      # the step call until it returns
+    "chunk.wait": "window",          # until its results are on the host
     "hosting.step": "hosting",       # hosted-app CPU tier per window
     "pcap.drain": "pcap",
     "tracker.heartbeat": "tracker",
     "checkpoint.save": "checkpoint",
     "digest.record": "digest",
+    "digest.pull": "digest",         # device -> host state transfer
+    "digest.hash": "digest",         # canonicalise, hash, chain
     "faults.apply": "faults",
     "report.finalize": "finalize",
     "build": "setup",
     # AOT executable cache (serving.aotcache, PR 13): the real XLA
     # build vs a persistent-cache load. Both nest inside the first
-    # chunk's compile+first_chunk span, whose SELF time (first-chunk
-    # execution + dispatch glue) stays under "compile" — so a phase
-    # map now states mechanically whether "cold" paid a compile
-    # (compile-miss > 0) or opened warm from disk (compile-hit only).
+    # chunk's chunk.dispatch, inside compile+first_chunk, whose SELF
+    # time (glue between its children) stays under "compile"; the
+    # first chunk's dispatch and wait are window work like any
+    # chunk's — so a phase map states mechanically whether "cold"
+    # paid a compile (compile-miss > 0) or opened warm from disk
+    # (compile-hit only).
     # tools/perf_regress.py's compile-bound exemption reads
     # compile-miss when present: a cache-hit run is gateable.
     "jitcache.compile": "compile-miss",

@@ -1,5 +1,6 @@
 """Span tracing: a low-overhead recorder emitting Chrome trace-event
-JSON (viewable in Perfetto / chrome://tracing).
+JSON (viewable in Perfetto / chrome://tracing), with every span also on
+the profiler's clock.
 
 The reference self-times its scheduler barriers and per-host exec
 seconds (shd-scheduler.c:250-252, shd-host.c:201-208) but only as
@@ -12,21 +13,38 @@ sim-time range, windows advanced and events executed in `args`, so
 sim-time progress and wall-clock cost correlate in a single view —
 "where does the wall time go" answered per chunk, not per run.
 
+Two sinks, one set of spans:
+
+- the JSON timeline (``--trace FILE``, ``obs.perf``,
+  ``tools/trace_report.py``): microseconds from tracer creation on
+  ``perf_counter``;
+- the profiler: a span opened with :meth:`Tracer.begin` is also a
+  ``jax.profiler.TraceAnnotation`` (a TraceMe) of the same name and
+  args, so whenever a ``jax.profiler`` session is active it lands in
+  the xspace's host plane, on the device trace's clock, beside the
+  device ops it waits on. With no session active a TraceMe records
+  nothing.
+
 Design constraints:
 
 - Cheap when disabled. `ENABLED` is a module-level boolean; hot paths
   (the per-chunk loop in engine.sim) guard every hook with a plain
-  ``if trace.ENABLED:`` so a run without ``--trace`` pays one boolean
-  check per chunk and allocates nothing. The ``span()`` context
-  manager is for cold paths only (setup, teardown, tools) — it
-  allocates a generator even when disabled.
-- Cheap when enabled. Recording a span is two perf_counter_ns reads
+  ``if trace.ENABLED:`` so a run without a tracer pays one boolean
+  check per hook and allocates nothing — no annotation either. The
+  ``span()`` context manager and the module-level ``begin``/``end``
+  are for cold paths only (setup, teardown, tools): they cost a call
+  even when disabled.
+- Cheap when enabled. A span is two perf_counter_ns reads, one TraceMe
+  (about a microsecond, nothing recorded outside a profiler session)
   and one list append of a small dict; serialization happens once, at
   flush. A hard cap (MAX_EVENTS) bounds memory on runaway loops; the
   drop count is recorded in the trace metadata.
 - One global tracer. Spans originate from several modules (engine,
   hosting, parallel, obs) on one thread of control; a process-global
   instance keeps the call sites to one import and one boolean.
+  :attr:`Tracer.tags` holds args every span begun in its scope
+  carries (the run loop sets ``run`` and ``chunk``), so spans opened
+  deep in a callee (``digest.pull``) share the run's identifiers.
 
 Timeline format: complete events (``"ph": "X"``) with microsecond
 ``ts``/``dur`` relative to tracer creation, wrapped as
@@ -36,13 +54,14 @@ this directly (catapult TraceEvent format).
 Usage:
 
     from shadow_tpu.obs import trace
-    trace.install("out.json")
+    trace.install("out.json")             # None: in memory only
     with trace.span("build"):             # cold path
         ...
     if trace.ENABLED:                     # hot path
-        t0 = trace.TRACER.now()
-        ...work...
-        trace.TRACER.complete("chunk", t0, args={"windows": 8})
+        tok = trace.TRACER.begin("chunk", sim_ns_start=t)
+    ...work...
+    if trace.ENABLED:
+        trace.TRACER.end(tok, windows=8)  # args known at the end
     trace.finish()                        # writes out.json
 """
 
@@ -61,28 +80,68 @@ TRACER = None
 MAX_EVENTS = 1_000_000
 
 
+def _annotation_class():
+    """jax.profiler.TraceAnnotation, or None where jax is absent (the
+    headless tools load this module without it)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
 class Tracer:
     """One trace session. `path=None` collects but discards at flush
     (non-writer processes of a multi-process mesh still time their
-    collectives so the collective call pattern stays uniform)."""
+    collectives so the collective call pattern stays uniform; an
+    in-memory harness reads :attr:`events` itself)."""
 
-    __slots__ = ("path", "events", "dropped", "_pid", "_epoch")
+    __slots__ = ("path", "events", "dropped", "tags", "_pid", "_epoch",
+                 "_ann")
 
     def __init__(self, path: str | None):
         self.path = path
         self.events = []
         self.dropped = 0
+        self.tags = {}
         self._pid = os.getpid()
         self._epoch = time.perf_counter_ns()
+        self._ann = _annotation_class()
 
     @staticmethod
     def now() -> int:
         """Span start stamp (perf_counter_ns) for complete()."""
         return time.perf_counter_ns()
 
+    def begin(self, name: str, **args):
+        """Open span `name` -> a token for :meth:`end`. The span
+        carries :attr:`tags` and `args`; it is also a profiler
+        TraceAnnotation until end()."""
+        if self.tags:
+            args = {**self.tags, **args}
+        ann = None
+        if self._ann is not None:
+            ann = self._ann(name, **args)
+            ann.__enter__()
+        return (name, time.perf_counter_ns(), args, ann)
+
+    def end(self, tok, **args):
+        """Close the span `tok` opened; `args` (counts known only at
+        the end) join its args on both sinks."""
+        name, t0, targs, ann = tok
+        if args:
+            targs = {**targs, **args}
+        if ann is not None:
+            if args:
+                ann.set_metadata(**args)
+            ann.__exit__(None, None, None)
+        self.complete(name, t0, targs or None)
+
     def complete(self, name: str, t0_ns: int, args: dict = None,
                  tid: int = 0):
-        """Record a complete span [t0_ns, now) named `name`."""
+        """Record a complete span [t0_ns, now) named `name` in the
+        JSON timeline only (a span from a past stamp cannot be
+        annotated for the profiler; :meth:`begin` can)."""
         if len(self.events) >= MAX_EVENTS:
             self.dropped += 1
             return
@@ -93,30 +152,6 @@ class Tracer:
         if args:
             ev["args"] = args
         self.events.append(ev)
-
-    def instant(self, name: str, args: dict = None, tid: int = 0):
-        """A zero-duration marker (``"ph": "i"``)."""
-        if len(self.events) >= MAX_EVENTS:
-            self.dropped += 1
-            return
-        ev = {"name": name, "ph": "i", "s": "p", "pid": self._pid,
-              "tid": tid,
-              "ts": (time.perf_counter_ns() - self._epoch) / 1000.0}
-        if args:
-            ev["args"] = args
-        self.events.append(ev)
-
-    def counter(self, name: str, values: dict, tid: int = 0):
-        """A counter track sample (``"ph": "C"``): `values` maps
-        series name -> number; Perfetto renders them as stacked
-        area tracks."""
-        if len(self.events) >= MAX_EVENTS:
-            self.dropped += 1
-            return
-        self.events.append(
-            {"name": name, "ph": "C", "pid": self._pid, "tid": tid,
-             "ts": (time.perf_counter_ns() - self._epoch) / 1000.0,
-             "args": values})
 
     def flush(self):
         """Serialize the timeline. No-op with path=None."""
@@ -154,18 +189,31 @@ def finish() -> Tracer | None:
     return tr
 
 
+def begin(name: str, **args):
+    """Cold-path :meth:`Tracer.begin` -> a token for :func:`end`, or
+    None when disabled."""
+    return (TRACER, TRACER.begin(name, **args)) if ENABLED else None
+
+
+def end(tok, **args):
+    """Cold-path :meth:`Tracer.end` on the tracer that began `tok`; a
+    None token is a no-op."""
+    if tok is not None:
+        tok[0].end(tok[1], **args)
+
+
 @contextmanager
 def span(name: str, **args):
     """Cold-path span context manager. NOT for per-chunk/per-event hot
     loops — the generator allocation is real even when disabled; hot
-    paths use the explicit ``if trace.ENABLED:`` + complete() pattern
-    (module docstring)."""
+    paths use the explicit ``if trace.ENABLED:`` + begin()/end()
+    pattern (module docstring)."""
     if not ENABLED:
         yield
         return
     tr = TRACER
-    t0 = tr.now()
+    tok = tr.begin(name, **args)
     try:
         yield
     finally:
-        tr.complete(name, t0, args or None)
+        tr.end(tok)

@@ -105,10 +105,10 @@ def gather_stats(stats) -> np.ndarray:
     from jax.experimental import multihost_utils
 
     from ..obs import trace as TR
-    t0 = TR.TRACER.now() if TR.ENABLED else 0
+    if TR.ENABLED:
+        tok = TR.TRACER.begin("dist.allgather")
     out = np.asarray(
         multihost_utils.process_allgather(stats, tiled=True))
     if TR.ENABLED:
-        TR.TRACER.complete("dist.allgather", t0,
-                           args={"bytes": int(out.nbytes)})
+        TR.TRACER.end(tok, bytes=int(out.nbytes))
     return out

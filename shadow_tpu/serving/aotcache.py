@@ -468,6 +468,8 @@ def load_or_compile(jitted, scope, sig, args, undonated=None):
             if u is not None:
                 jitted = u
         key = entry_key(scope, sig)
+        # a load is known to be one only once it hits: the span is
+        # recorded from its stamp then, in the JSON timeline alone
         t0 = TR.TRACER.now() if TR.ENABLED else None
         w0 = time.perf_counter()
         fn = cache.load(key)
@@ -484,16 +486,16 @@ def load_or_compile(jitted, scope, sig, args, undonated=None):
                 g = reg.gauge("jitcache.load_wall_s")
                 g.set(g.v + wall)
             return fn
-    t0 = TR.TRACER.now() if TR.ENABLED else None
+    if TR.ENABLED:
+        tok = TR.TRACER.begin("jitcache.compile", scope=scope or "?",
+                              cached=key is not None)
     w0 = time.perf_counter()
     compiled = jitted.lower(*args).compile()
     wall = time.perf_counter() - w0
     STATS["compiles"] += 1
     STATS["compile_wall_s"] += wall
     if TR.ENABLED:
-        TR.TRACER.complete("jitcache.compile", t0,
-                           args={"scope": scope or "?",
-                                 "cached": key is not None})
+        TR.TRACER.end(tok)
     if MT.ENABLED:
         reg = MT.REGISTRY
         reg.counter("jitcache.compiles").inc()

@@ -32,11 +32,69 @@ jax.config.update("jax_platforms", _platform)
 import pytest  # noqa: E402
 
 
+# Under pytest-xdist's --dist loadfile each worker runs whole files, and
+# xdist hands out the files with the most tests first. The files whose
+# tests compile large window programs hold few tests, so they would
+# start last and the run would end on them, past the tier-1 limit.
+# These files are handed out first instead, longest first (per-file
+# walls of a six-worker run on the CPU: test_compaction.py about 950 s
+# down to test_autotune.py about 65 s), then the rest as xdist orders
+# them. A new file that compiles a TCP window program belongs here.
+HEAVY_FILES = (
+    "test_compaction.py", "test_until_complete.py", "test_tgen.py",
+    "test_differential.py", "test_compaction_invariants.py",
+    "test_tcp.py", "test_multiproc.py", "test_distributed.py",
+    "test_parallel.py", "test_socks.py", "test_hosting.py",
+    "test_netscope.py", "test_shim.py", "test_checkpoint.py",
+    "test_tpu_compile.py", "test_shrink.py", "test_obs.py",
+    "test_gossip.py", "test_harness.py",
+    "test_loopback.py", "test_digest.py", "test_faults.py",
+    "test_supervision.py", "test_serving.py", "test_autotune.py",
+)
+# Ahead of them, two files whose checks read the process or the machine
+# and so want a fresh worker beside workers that are still collecting
+# and tracing in Python: test_shim_libc.py's sleep test (first in its
+# file) bounds its own wall, compile included, at 60 s (about 47 s on
+# an idle machine; 63 s beside the TCP compiles), and
+# test_memscope.py's watermark test needs the worker's resident set to
+# fall by 128 MiB when it frees 256 MiB, which it did not in a worker
+# holding 5 GB from the heavy files run before it.
+FIRST_FILES = ("test_shim_libc.py", "test_memscope.py")
+ORDER = FIRST_FILES + HEAVY_FILES
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "slow: excluded from the tier-1 run (-m 'not slow'); "
         "compile-heavy or long-wall tests")
+    if (getattr(config.option, "dist", "no") == "loadfile"
+            and hasattr(config.option, "loadscopereorder")):
+        # the controller's scheduler keeps the workers' collection
+        # order, which pytest_collection_modifyitems sets
+        config.option.loadscopereorder = False
+
+
+def pytest_collection_modifyitems(config, items):
+    # an xdist worker (its dist option reads "no") reports this order
+    # to the controller's scheduler; a run without xdist keeps its own
+    if not hasattr(config, "workerinput"):
+        return
+
+    def file_of(item):
+        return item.nodeid.split("::", 1)[0]
+
+    count = {}
+    for item in items:
+        count[file_of(item)] = count.get(file_of(item), 0) + 1
+
+    def rank(item):
+        f = file_of(item)
+        name = f.rsplit("/", 1)[-1]
+        if name in ORDER:
+            return (ORDER.index(name), 0)
+        return (len(ORDER), -count[f])
+    items.sort(key=rank)
 
 
 @pytest.fixture(scope="session")

@@ -1,9 +1,15 @@
 """Observability tests: span tracing, metrics registry, heartbeat
-tracker, pcap capture, logger."""
+tracker, pcap capture, logger, and one timeline: the run loop's spans
+on the profiler's clock and the window program's pass labels read from
+its own executable."""
 
+import glob
 import json
+import os
 import struct
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -149,6 +155,49 @@ def test_trace_span_nesting(tmp_path):
     assert by_name["hot"]["args"] == {"n": 3}
     # metadata names the process for Perfetto
     assert any(e.get("ph") == "M" for e in doc["traceEvents"])
+
+
+def test_trace_begin_end_on_both_sinks(monkeypatch):
+    """A begin/end span lands in the JSON sink and, as a profiler
+    TraceAnnotation of the same name and args, on the profiler's
+    clock; the tracer's tags join every span begun under them; with
+    nothing installed the cold-path helpers are no-ops."""
+    made = []
+
+    class Ann:
+        def __init__(self, name, **kw):
+            self.name, self.kw, self.state = name, dict(kw), "new"
+            made.append(self)
+
+        def __enter__(self):
+            self.state = "open"
+            return self
+
+        def __exit__(self, *exc):
+            self.state = "closed"
+
+        def set_metadata(self, **kw):
+            self.kw.update(kw)
+
+    monkeypatch.setattr(T, "_annotation_class", lambda: Ann)
+    assert T.begin("never") is None
+    T.end(None)
+    tr = T.install(None)
+    tr.tags = {"run": 7}
+    tok = tr.begin("chunk", sim_ns_start=1)
+    inner = T.begin("chunk.wait")
+    assert [a.state for a in made] == ["open", "open"]
+    T.end(inner)
+    tr.end(tok, windows=3)
+    T.finish()
+    assert [(a.name, a.state) for a in made] == [("chunk", "closed"),
+                                                 ("chunk.wait", "closed")]
+    assert made[0].kw == {"run": 7, "sim_ns_start": 1, "windows": 3}
+    by = {e["name"]: e for e in tr.events}
+    assert by["chunk"]["args"] == made[0].kw
+    assert by["chunk.wait"]["args"] == {"run": 7}
+    c, w = by["chunk"], by["chunk.wait"]
+    assert c["ts"] <= w["ts"] and w["ts"] + w["dur"] <= c["ts"] + c["dur"]
 
 
 def test_trace_disabled_span_is_noop(tmp_path):
@@ -387,3 +436,179 @@ def test_delivery_status_trail(tmp_path):
             else:
                 saw_rx = True
     assert saw_tx and saw_rx
+
+
+# --- one timeline ------------------------------------------------------
+#
+# obs.trace's begin/end spans are also jax.profiler TraceAnnotations, so
+# a profiled run's xspace host plane holds the same spans as the JSON
+# sink; core.jitcache.AotJit.scope_map maps each executable's HLO
+# instruction names to their named_scope paths (obs.passcope.
+# scope_map_of), the map a trace taken without HLO protos lacks. These
+# tests run the ping program the tests above compile (CFG).
+
+SPANS = ("chunk", "chunk.dispatch", "chunk.wait", "digest.record",
+         "digest.pull", "digest.hash")
+
+
+def _ping_sim():
+    return Simulation(scen(stop=6),
+                      engine_cfg=EngineConfig(num_hosts=2, **CFG))
+
+
+def _profile(tmp, fn, hlo=True):
+    """Run fn() under jax.profiler -> the .xplane.pb path."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.enable_hlo_proto = hlo
+    jax.profiler.start_trace(str(tmp), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    return glob.glob(os.path.join(str(tmp), "**", "*.xplane.pb"),
+                     recursive=True)[0]
+
+
+@pytest.fixture(scope="module")
+def untraced(tmp_path_factory):
+    """The ping program run with no tracer installed, counting every
+    annotation made and every scope map built."""
+    from shadow_tpu.engine.window import run_windows_aot
+    from shadow_tpu.obs import passcope
+    made = {"ann": 0, "maps": 0}
+    real_ann, real_map = jax.profiler.TraceAnnotation, passcope.scope_map_of
+
+    class Counting(real_ann):
+        def __init__(self, *a, **kw):
+            made["ann"] += 1
+            super().__init__(*a, **kw)
+
+    def counting_map(compiled):
+        made["maps"] += 1
+        return real_map(compiled)
+    mp = pytest.MonkeyPatch()
+    mp.setattr(jax.profiler, "TraceAnnotation", Counting)
+    mp.setattr(passcope, "scope_map_of", counting_map)
+    try:
+        assert not T.ENABLED
+        sim = _ping_sim()
+        report = sim.run(digest=str(tmp_path_factory.mktemp("dg") / "c"))
+        prog = run_windows_aot(sim.cfg, sim.effective_chunk(64))
+        return made, report, prog
+    finally:
+        mp.undo()
+
+
+def test_tracer_off_makes_no_annotation_and_no_scope_map(untraced):
+    from shadow_tpu.core.jitcache import AotJit
+    made, report, prog = untraced
+    assert report.events > 0 and prog._compiled
+    assert made == {"ann": 0, "maps": 0}
+    # a program materialised with tracing off holds no map
+    fresh = AotJit(lambda x: jnp.cumsum(x) * 2)
+    fresh(jnp.arange(7))
+    assert fresh._compiled and fresh._scopes is None
+
+
+def test_spans_on_the_profiler_clock_match_the_json_sink(untraced,
+                                                         tmp_path):
+    from jax.profiler import ProfileData
+    T.install(None)
+    try:
+        xp = _profile(tmp_path, lambda: _ping_sim().run(
+            digest=str(tmp_path / "chain")), hlo=False)
+    finally:
+        tr = T.finish()
+    js = sorted((e for e in tr.events if e["name"] in SPANS),
+                key=lambda e: (e["ts"], -e["dur"]))
+    host = []
+    for plane in ProfileData.from_file(xp).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                host += [e for e in line.events
+                         if e.name.split("#")[0] in SPANS]
+    host.sort(key=lambda e: (e.start_ns, -e.duration_ns))
+    names = [e["name"] for e in js]
+    assert names == [e.name.split("#")[0] for e in host]
+    assert set(names) == set(SPANS)
+    # one run: every span carries the run's id and its chunk
+    runs = {e["args"]["run"] for e in js}
+    assert len(runs) == 1
+    assert all("chunk" in e["args"] for e in js)
+    pulls = [e for e in js if e["name"] == "digest.pull"]
+    assert all(e["args"]["bytes"] > 0 for e in pulls)
+    # the same durations within a tolerance, and the same nesting
+    t0_js, t0_host = js[0]["ts"], host[0].start_ns
+    for j, h in zip(js, host):
+        assert abs(j["dur"] - h.duration_ns / 1e3) <= 0.05 * j["dur"] + 200
+        assert abs((j["ts"] - t0_js)
+                   - (h.start_ns - t0_host) / 1e3) <= 1000
+
+    def inside(a, b, s, d):      # span a (start, dur) within span b
+        return s(b) <= s(a) and s(a) + d(a) <= s(b) + d(b) + 1
+
+    for kid, parent in (("chunk.dispatch", "chunk"),
+                        ("chunk.wait", "chunk"),
+                        ("digest.pull", "digest.record"),
+                        ("digest.hash", "digest.record")):
+        for evs, s, d, nm in (
+                (js, lambda e: e["ts"], lambda e: e["dur"],
+                 lambda e: e["name"]),
+                (host, lambda e: e.start_ns / 1e3,
+                 lambda e: e.duration_ns / 1e3,
+                 lambda e: e.name.split("#")[0])):
+            ps = [e for e in evs if nm(e) == parent]
+            for k in (e for e in evs if nm(e) == kid):
+                assert any(inside(k, p, s, d) for p in ps), (kid, parent)
+
+
+def test_scope_map_of_equals_the_trace_scope_map(untraced, tmp_path):
+    """The executable's own HLO names its ops' scopes exactly as the
+    HLO proto a trace embeds does."""
+    from shadow_tpu.core.simtime import SIMTIME_MAX
+    from shadow_tpu.engine.window import (chunk_program_scopes,
+                                          run_windows_aot)
+    from shadow_tpu.obs import passcope
+    _, _, prog = untraced
+    sim = _ping_sim()
+    chunk = sim.effective_chunk(64)
+    # the run loop's own first window: the signature it compiled for
+    t0 = jnp.min(sim.hosts.eq_next)
+    args = (sim.hosts, sim.hp, sim.sh, t0,
+            jnp.where(t0 == SIMTIME_MAX, t0, t0 + sim.sh.min_jump))
+    exe = run_windows_aot(sim.cfg, chunk).warm(*args)
+    jax.block_until_ready(args)
+    xp = _profile(tmp_path, lambda: jax.block_until_ready(exe(*args)))
+    (module, mine), = passcope.scope_map_of(exe).items()
+    assert mine and passcope.hlo_scope_map(xp, module) == mine
+    labels = {p for op in mine.values() if op for p in op.split("/")}
+    assert {"drain", "exchange", "advance"} <= labels
+    # the on-request maps over the process's chunk programs hold it,
+    # under its module's name: the programs' instruction names collide
+    assert prog.scope_map()[module] == mine
+    assert chunk_program_scopes()[module] == mine
+
+
+def test_scope_map_built_on_request():
+    """A run builds no scope map, traced or not: AotJit.scope_map()
+    reads it on request, once per executable."""
+    from shadow_tpu.core.jitcache import AotJit
+
+    def f(x):
+        with jax.named_scope("exchange"):
+            return jnp.cumsum(x) * 2
+
+    off, on = AotJit(f), AotJit(f)
+    off(jnp.arange(7))
+    T.install(None)
+    try:
+        on(jnp.arange(7))
+    finally:
+        T.finish()
+    assert off._scopes is None and on._scopes is None
+    m = on.scope_map()
+    (module, ops), = m.items()
+    assert any("exchange" in (op or "") for op in ops.values())
+    assert len(on._scopes) == 1
+    assert off.scope_map() == m and on.scope_map() == m

@@ -114,6 +114,23 @@ def test_self_times_are_stack_based(tmp_path):
     assert selfs["sort.2"] == 4 * MS
 
 
+def test_labels_under_a_transform_are_peeled():
+    """The handlers run vmapped over the drain's lanes, so jax renders
+    their scopes as ``vmap(tcp.rx)`` on the op_name path: they label
+    their ops as plain scopes do, and an unnamed ``vmap()`` is no
+    label."""
+    base = "jit(run_windows_v0)/while/body/drain/while/body/dense/"
+    dev = PC.attribute(
+        {"and.1": 4, "select.2": 3, "eq.3": 2, "or.4": 1},
+        {"and.1": base + "vmap(tcp.rx)/jit(_where)/and",
+         "select.2": base + "vmap(nic.tx)/select_n",
+         "eq.3": base + "vmap()/eq",
+         "or.4": "jit(f)/vmap(jit(exchange))/or"})
+    assert {k: v["frac"] for k, v in dev["phases"].items()} == {
+        "tcp.rx": 0.4, "nic.tx": 0.3, "drain": 0.2, "exchange": 0.1}
+    assert dev["attributed_frac"] == 1.0
+
+
 def test_runtime_scaffolding_excluded_from_denominator():
     selfs = {"ThunkExecutor::Execute (wait for completion)": 900 * MS,
              "fusion.1": 100 * MS}
