@@ -1645,7 +1645,7 @@ class PyEngine:
 
         Mirror of engine.window._drain_hot/_pass_hot: the same
         searchsorted rung selection over the same ladders, the same
-        per-pass event budget (sparse_batch events per gathered host on
+        per-pass event budget (rung_batch events per gathered host on
         sparse rungs, one per ready host on dense), the same fixed
         active set inside a window rung with inner passes tallied into
         the w slot. State-identical to the plain per-host drain — hosts
@@ -1654,11 +1654,10 @@ class PyEngine:
         pass_acc so occupancy math can be recounted independently.
         -> events executed."""
         import bisect
-        from .window import ladder_of, sparse_batch, window_ladder
+        from .window import ladder_of, rung_batch, window_ladder
         cfg = self.cfg
         wks = window_ladder(cfg, self.H)
         ks = ladder_of(cfg, self.H)
-        B = sparse_batch(cfg)
         nexec = 0
 
         def run_pass(ready, batch):
@@ -1684,7 +1683,8 @@ class PyEngine:
                     break
                 self.pass_mix[lbl] = self.pass_mix.get(lbl, 0) + 1
                 r = bisect.bisect_left(sub_ks, len(ready))
-                nexec += run_pass(ready, B if r < len(sub_ks) else 1)
+                nexec += run_pass(ready, rung_batch(cfg, sub_ks[r])
+                                  if r < len(sub_ks) else 1)
         else:
             while True:
                 ready = [h for h in self.hosts
@@ -1698,7 +1698,7 @@ class PyEngine:
                     r = bisect.bisect_left(ks, len(ready))
                     sparse = r < len(ks)
                     lbl = "k%d" % ks[r] if sparse else "dense"
-                    batch = B if sparse else 1
+                    batch = rung_batch(cfg, ks[r]) if sparse else 1
                 self.pass_mix[lbl] = self.pass_mix.get(lbl, 0) + 1
                 nexec += run_pass(ready, batch)
         return nexec

@@ -201,7 +201,7 @@ class SimReport:
             return {}
         rb = self.cost["row_bytes"]
         mix = self.cost["pass_mix"]       # {label: (K or H, passes)}
-        B = self.cost.get("batch", 1)
+        B = self.cost.get("batch", {})    # {label: events per lane}
         per_chip_h = self.cost["per_chip_hosts"]
         shards = self.cost.get("shards", 1)
         passes = {k: int(n) for k, (_, n) in mix.items()}
@@ -209,14 +209,15 @@ class SimReport:
         est_pass_bytes = {}
         est_total = 0
         for label, (k, n) in mix.items():
+            b = B.get(label, 1)
             if label == "dense":
                 pb = 2 * per_chip_h * rb
             elif label.startswith("w"):
                 # window-rung: gather/scatter amortized over the whole
-                # window; each counted pass drains B batched sub-steps
-                pb = 2 * B * k * rb
+                # window; each counted pass drains b batched sub-steps
+                pb = 2 * b * k * rb
             else:
-                pb = (4 + 2 * B) * k * rb
+                pb = (4 + 2 * b) * k * rb
             est_pass_bytes[label] = pb
             est_total += pb * int(n)
         warm = self.cost.get("warm_wall")
@@ -1162,7 +1163,7 @@ class Simulation:
 
         # cost-model bookkeeping (SimReport.cost_model): pass mix per
         # compaction rung + per-row state bytes
-        from .window import pass_labels, sparse_batch
+        from .window import pass_batches, pass_labels
         _pl = pass_labels(cfg, per_chip_h)
         _pass_labels = [lbl for lbl, _ in _pl]
         _pass_sizes = [size for _, size in _pl]
@@ -1170,7 +1171,7 @@ class Simulation:
         # lockstep occupancy (obs.passcope): lane utilization from the
         # SAME pass accounting — pure host arithmetic over the rung
         # counts the drain already returns, so it is always on
-        _batch = sparse_batch(cfg)
+        _batch = pass_batches(cfg, per_chip_h)
 
         def occ_now(events):
             return PC.occupancy(
@@ -1678,7 +1679,7 @@ class Simulation:
             "hot_columns": len(_hot),
             "pass_mix": {lbl: (size, int(nn)) for lbl, size, nn in
                          zip(_pass_labels, _pass_sizes, pass_acc)},
-            "batch": sparse_batch(cfg),
+            "batch": _batch,
             "per_chip_hosts": per_chip_h,
             "shards": (1 if mesh is None else
                        cfg.num_hosts // per_chip_h),

@@ -93,12 +93,21 @@ class EngineConfig:
     #   per event. -1 (default) = AUTO: a small rung ladder sized from
     #   num_hosts, each pass picking the smallest rung that fits its
     #   ready count (engine.window.ladder_of — replaces the round-3
-    #   hand-tuned per-config constant), plus ONE window-level rung
-    #   under the quarter rule 4K <= H (engine.window.window_ladder;
-    #   round 9 tightened it from 2K <= H after the paired phold-4096
-    #   A/B showed the half-state [2048] window rung losing 1.2x to
-    #   [512] — BASELINE.md round-9 table, tools/perf_ab.py). > 0 =
-    #   one explicit rung of that size. 0 = off (always dense).
+    #   hand-tuned per-config constant): of 32, 256 and 512 rows, the
+    #   smallest and the largest with 4K <= H. The 256 rung (1,024 <=
+    #   H < 2,048) drains ONE event per gathered host
+    #   (engine.window.rung_batch) and runs the wider passes too, 256
+    #   rows at a time: a batch of 16 would step more lanes than the
+    #   dense pass it replaces, one event keeps the dense pass
+    #   schedule (pass count, windows and state unchanged), and a
+    #   third copy of the handler machine would add about 32 MB of
+    #   device code (hbm_peak_gb at socks-2k).
+    #   Plus ONE window-level rung under the quarter rule 4K <= H
+    #   (engine.window.window_ladder; round 9 tightened it from 2K <=
+    #   H after the paired phold-4096 A/B showed the half-state [2048]
+    #   window rung losing 1.2x to [512] — BASELINE.md round-9 table,
+    #   tools/perf_ab.py). > 0 = one explicit rung of that size. 0 =
+    #   off (always dense).
     #   Bit-identical in every mode: hosts only interact at window
     #   boundaries, so per-host (time, seq) execution order is
     #   unchanged.

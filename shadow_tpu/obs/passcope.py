@@ -454,9 +454,9 @@ def occupancy(pass_mix, events, batch):
     (engine.window.pass_labels order: w-rungs, k-rungs, dense).
     events: executed events over the same span (chained NIC-TX
     included, so utilization is clamped at 1.0).
-    batch: the sparse event batch (engine.window.sparse_batch) — a
-    sparse pass runs `batch` event slots per gathered lane; dense
-    passes run one.
+    batch: {label: event slots per lane per pass} —
+    engine.window.pass_batches, the batch the drain itself runs on
+    each rung (a label it does not name runs one, as dense does).
 
     A w-rung's counted passes run over its gathered width; inner
     sub-compaction (a k-rung pass inside a w-window) is not counted
@@ -489,7 +489,7 @@ def occupancy(pass_mix, events, batch):
         return 0.0
     for lbl, (width, n) in pass_mix.items():
         width, n = int(width), int(n)
-        b = 1 if lbl == "dense" else max(1, int(batch))
+        b = max(1, int(batch.get(lbl, 1)))
         steps = n * width * b
         lane_steps += steps
         passes += n
@@ -515,7 +515,8 @@ def shard_occupancy(shard_pass_acc, shard_events, labels_sizes, batch):
 
     shard_pass_acc: [n_shards][n_rungs] pass counts;
     shard_events: [n_shards] executed events;
-    labels_sizes: [(label, width)] in pass-index order.
+    labels_sizes: [(label, width)] in pass-index order;
+    batch: {label: batch} as occupancy takes it.
 
     -> {"per_shard": [waste_frac...], "utilization": [...],
         "skew": max/mean of per-shard utilization (1.0 = balanced)}
@@ -744,7 +745,7 @@ def self_check(path=None):
     assert dev["rungs"]["w512"]["ms"] == 90.0, dev
     # occupancy arithmetic, exactly
     occ = occupancy({"k32": (32, 10), "dense": (64, 2)},
-                    events=200, batch=4)
+                    events=200, batch={"k32": 4, "dense": 1})
     assert occ["lane_steps"] == 10 * 32 * 4 + 2 * 64 * 1, occ
     assert occ["passes"] == 12, occ
     assert occ["utilization"] == round(200 / 1408, 4), occ
@@ -752,7 +753,7 @@ def self_check(path=None):
     assert occ["per_rung"]["k32"]["min_fill"] == round(1 / 32, 4), occ
     assert occ["per_rung"]["dense"]["min_fill"] == round(33 / 64, 4), occ
     sh = shard_occupancy([[10, 2], [2, 0]], [200, 40],
-                         [("k32", 32), ("dense", 64)], 4)
+                         [("k32", 32), ("dense", 64)], {"k32": 4})
     assert len(sh["per_shard"]) == 2 and sh["skew"] >= 1.0, sh
     print("passcope: self-check OK (decoder + attribution + occupancy)")
     return 0

@@ -126,8 +126,8 @@ def run_batch(sims, names=None, digest_paths=None, digest_every=0,
     from ..engine import defs
     from ..engine.sim import SimReport
     from ..engine.state import hot_fields
-    from ..engine.window import (pass_labels, run_windows_batch_aot,
-                                 sparse_batch)
+    from ..engine.window import (pass_batches, pass_labels,
+                                 run_windows_batch_aot)
     from ..obs import digest as DG
     from ..obs import netscope as NSC
     from ..obs import passcope as PCOPE
@@ -309,7 +309,7 @@ def run_batch(sims, names=None, digest_paths=None, digest_every=0,
             "hot_columns": len(_hot),
             "pass_mix": {lbl: (size, int(nn)) for (lbl, size), nn in
                          zip(_pl, pass_acc[i])},
-            "batch": sparse_batch(cfg),
+            "batch": pass_batches(cfg, H),
             "per_chip_hosts": H,
             "shards": 1,
             "warm_wall": warm,

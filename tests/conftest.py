@@ -49,7 +49,8 @@ HEAVY_FILES = (
     "test_tpu_compile.py", "test_shrink.py", "test_obs.py",
     "test_gossip.py", "test_harness.py",
     "test_loopback.py", "test_digest.py", "test_faults.py",
-    "test_supervision.py", "test_serving.py", "test_autotune.py",
+    "test_supervision.py", "test_serving.py", "test_ladder.py",
+    "test_autotune.py",
 )
 # Ahead of them, two files whose checks read the process or the machine
 # and so want a fresh worker beside workers that are still collecting

@@ -150,7 +150,7 @@ def test_decode_dir_missing_raises(tmp_path):
 
 def test_occupancy_arithmetic_exact():
     occ = PC.occupancy({"k32": (32, 10), "dense": (64, 2)},
-                       events=200, batch=4)
+                       events=200, batch={"k32": 4, "dense": 1})
     # 10 sparse passes x 32 lanes x batch 4 + 2 dense x 64 x 1
     assert occ["lane_steps"] == 1408
     assert occ["passes"] == 12
@@ -165,14 +165,16 @@ def test_occupancy_arithmetic_exact():
 
 def test_occupancy_utilization_clamped():
     # chained NIC-TX events can exceed lane-step slots; clamp at 1.0
-    occ = PC.occupancy({"dense": (4, 1)}, events=100, batch=1)
+    occ = PC.occupancy({"dense": (4, 1)}, events=100,
+                       batch={"dense": 1})
     assert occ["utilization"] == 1.0
     assert occ["waste_frac"] == 0.0
 
 
 def test_shard_occupancy_skew():
     sh = PC.shard_occupancy([[10, 2], [2, 0]], [200, 40],
-                            [("k32", 32), ("dense", 64)], 4)
+                            [("k32", 32), ("dense", 64)],
+                            {"k32": 4, "dense": 1})
     assert len(sh["per_shard"]) == 2
     assert sh["skew"] >= 1.0
     assert all(0.0 <= w <= 1.0 for w in sh["per_shard"])
@@ -229,7 +231,7 @@ def test_capture_result_without_trace(tmp_path):
 def test_publish_lands_metrics_sections():
     reg = MT.Registry()
     occ = PC.occupancy({"k32": (32, 10), "dense": (64, 2)},
-                       events=200, batch=4)
+                       events=200, batch={"k32": 4, "dense": 1})
     dev = PC.attribute(
         {"fusion.1": 10 * MS}, {"fusion.1": "jit(f)/jit(main)/drain/x"})
     dev["available"] = True
@@ -251,7 +253,8 @@ def test_format_report_warns_below_floor():
         {"fusion.1": 10 * MS, "mystery.2": 90 * MS},
         {"fusion.1": "jit(f)/jit(main)/drain/x"})
     dev.update(available=True, chunks_traced=3)
-    occ = PC.occupancy({"dense": (16, 4)}, events=20, batch=1)
+    occ = PC.occupancy({"dense": (16, 4)}, events=20,
+                       batch={"dense": 1})
     txt = PC.format_report(dev, occ)
     assert "WARNING" in txt and "mystery.2" in txt
     assert "waste_frac" in txt and "rung dense" in txt
@@ -283,7 +286,7 @@ def test_pyengine_recount_is_state_identical():
 def test_pyengine_recount_occupancy_bounds():
     from shadow_tpu.engine.pyengine import PyEngine
     from shadow_tpu.engine.sim import Simulation
-    from shadow_tpu.engine.window import pass_labels, sparse_batch
+    from shadow_tpu.engine.window import pass_batches, pass_labels
     sim = Simulation(_recount_scen())
     cfg = sim.cfg
     eng = PyEngine(sim, count_passes=True)
@@ -291,7 +294,7 @@ def test_pyengine_recount_occupancy_bounds():
     widths = dict(pass_labels(cfg, cfg.num_hosts))
     occ = PC.occupancy(
         {lbl: (widths[lbl], n) for lbl, n in eng.pass_mix.items()},
-        int(stats[:, 0].sum()), sparse_batch(cfg))
+        int(stats[:, 0].sum()), pass_batches(cfg))
     assert 0.0 <= occ["waste_frac"] <= 1.0
     # a lockstep pass can never run more events than lane-steps
     assert occ["utilization"] <= 1.0
@@ -412,7 +415,7 @@ def test_compiled_pass_mix_matches_pyengine_recount():
     occupancy table is provably the drain's own accounting."""
     from shadow_tpu.engine.pyengine import PyEngine
     from shadow_tpu.engine.sim import Simulation
-    from shadow_tpu.engine.window import pass_labels, sparse_batch
+    from shadow_tpu.engine.window import pass_batches, pass_labels
     scen = _recount_scen(n=128, stop=2)
     rep = Simulation(scen).run()
     eng = PyEngine(Simulation(scen), count_passes=True)
@@ -426,5 +429,5 @@ def test_compiled_pass_mix_matches_pyengine_recount():
     widths = dict(pass_labels(cfg, cfg.num_hosts))
     occ = PC.occupancy(
         {lbl: (widths[lbl], n) for lbl, n in eng.pass_mix.items()},
-        int(py_stats[:, 0].sum()), sparse_batch(cfg))
+        int(py_stats[:, 0].sum()), pass_batches(cfg))
     assert occ["waste_frac"] == rep.occupancy["waste_frac"]
